@@ -57,6 +57,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="fewer seeds/requests (CI mode)")

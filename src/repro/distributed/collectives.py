@@ -14,7 +14,6 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -61,11 +60,11 @@ def seq_sharded_decode(mesh: Mesh, kv_axes: Sequence[str]):
     def fn(q, k, v):
         qspec = P(None, None, None)
         kvspec = P(None, axes if len(axes) > 1 else axes[0], None, None)
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(qspec, kvspec, kvspec),
             out_specs=qspec,
-            check_rep=False,
+            check_vma=False,
         )(q, k, v)
 
     return fn

@@ -10,8 +10,11 @@ for a single kernel launch (TPU-native analogue of the paper's HAProxy+Lua
 
 Layout: everything kept 2D with the pair axis last (lane dimension,
 padded to a multiple of 128 by ops.py). Single program, grid=().
-VMEM: 3 x (G x P') profile tables + (1 x P') queue + (W x 1) ids — a P'=1024,
-G=8, W=4096 window uses ~130 KiB.
+VMEM: 3 x (G x P') profile tables + (1 x P') queue + (W x 1) ids and
+choices — a P'=1024, G=8, W=4096 window holds ~100 KiB of tables; the
+(W x 1) int32 blocks take 2 MiB each if padded to 128 lanes. Rows are read
+from the refs (``ref[pl.ds(g, 1), :]``) and each choice is stored as a
+(1, 1) block: the forms Mosaic lowers.
 """
 
 from __future__ import annotations
@@ -25,16 +28,24 @@ from jax.experimental import pallas as pl
 BIG = 1e30
 
 
+def _first_argmin(J, lane):
+    """Lane of the first minimum of the (1, P') row ``J``: ``jnp.argmin``'s
+    tie rule, written out because the compiled kernel's ``jnp.argmin``
+    does not keep it. On a v5e it picked another of two exactly tied
+    pairs for over half of the paper fleet's requests."""
+    return jnp.min(jnp.where(J == jnp.min(J), lane, J.shape[1]))
+
+
 def _moscore_kernel(tg_ref, eg_ref, mg_ref, g_ref, q0_ref, out_ref, qf_ref,
                     *, delta: float, gamma: float, n_window: int):
     # tg/eg/mg: (G, P') profiles transposed; g: (W, 1) int32; q0: (1, P')
     _, p = tg_ref.shape
 
     def body(w, q):
-        g = g_ref[w, 0]
-        Tg = jax.lax.dynamic_slice(tg_ref[...], (g, 0), (1, p))   # (1, P')
-        Eg = jax.lax.dynamic_slice(eg_ref[...], (g, 0), (1, p))
-        Mg = jax.lax.dynamic_slice(mg_ref[...], (g, 0), (1, p))
+        row = pl.ds(g_ref[w, 0], 1)
+        Tg = tg_ref[row, :]                                        # (1, P')
+        Eg = eg_ref[row, :]
+        Mg = mg_ref[row, :]
 
         feasible = Mg >= jnp.max(Mg) - delta
         L = Tg * (1.0 + q)
@@ -46,12 +57,10 @@ def _moscore_kernel(tg_ref, eg_ref, mg_ref, g_ref, q0_ref, out_ref, qf_ref,
         En = (Eg - e_min) / jnp.maximum(e_max - e_min, 1e-9)
         J = jnp.where(feasible, gamma * Ln + (1.0 - gamma) * En, BIG)
 
-        sel = jnp.argmin(J[0]).astype(jnp.int32)
-        # index with a traced scalar, not a python int: older jax pallas
-        # rejects raw ints in store indexers
-        pl.store(out_ref, (w, jnp.asarray(0, jnp.int32)), sel)
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (1, p), 1) == sel)
-        return q + onehot.astype(q.dtype)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+        sel = _first_argmin(J, lane)
+        out_ref[pl.ds(w, 1), :] = jnp.full((1, 1), sel, jnp.int32)
+        return q + (lane == sel).astype(q.dtype)
 
     q = jax.lax.fori_loop(0, n_window, body, q0_ref[...].astype(jnp.float32))
     qf_ref[...] = q.astype(qf_ref.dtype)
@@ -71,10 +80,10 @@ def _moscore_hoisted_kernel(tg_ref, en_ref, fs_ref, g_ref, q0_ref, out_ref,
     _, p = tg_ref.shape
 
     def body(w, q):
-        g = g_ref[w, 0]
-        Tg = jax.lax.dynamic_slice(tg_ref[...], (g, 0), (1, p))   # (1, P')
-        En = jax.lax.dynamic_slice(en_ref[...], (g, 0), (1, p))
-        feas = jax.lax.dynamic_slice(fs_ref[...], (g, 0), (1, p)) > 0.0
+        row = pl.ds(g_ref[w, 0], 1)
+        Tg = tg_ref[row, :]                                        # (1, P')
+        En = en_ref[row, :]
+        feas = fs_ref[row, :] > 0.0
 
         L = Tg * (1.0 + q)
         l_min = jnp.min(jnp.where(feas, L, BIG))
@@ -82,10 +91,10 @@ def _moscore_hoisted_kernel(tg_ref, en_ref, fs_ref, g_ref, q0_ref, out_ref,
         Ln = (L - l_min) / jnp.maximum(l_max - l_min, 1e-9)
         J = jnp.where(feas, gamma * Ln + (1.0 - gamma) * En, BIG)
 
-        sel = jnp.argmin(J[0]).astype(jnp.int32)
-        pl.store(out_ref, (w, jnp.asarray(0, jnp.int32)), sel)
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (1, p), 1) == sel)
-        return q + onehot.astype(q.dtype)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+        sel = _first_argmin(J, lane)
+        out_ref[pl.ds(w, 1), :] = jnp.full((1, 1), sel, jnp.int32)
+        return q + (lane == sel).astype(q.dtype)
 
     q = jax.lax.fori_loop(0, n_window, body, q0_ref[...].astype(jnp.float32))
     qf_ref[...] = q.astype(qf_ref.dtype)

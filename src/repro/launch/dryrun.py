@@ -21,7 +21,6 @@ unfused execution — both the XLA and analytic numbers are recorded.
 """
 
 import argparse
-import contextlib
 import json
 import time
 import traceback
@@ -46,18 +45,12 @@ def _compile_once(cell_builder, mesh, unroll_map):
     try:
         cell = cell_builder()
         in_sh = cell.in_shardings(mesh)
-        # jax >= 0.6 wants the mesh context for Auto-axis jit; older jax has
-        # no set_mesh and takes the mesh purely from in_shardings
-        ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") \
-            else contextlib.nullcontext()
-        with ctx:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(cell.step_fn, in_shardings=in_sh,
                               donate_argnums=cell.donate
                               ).lower(*cell.abstract_args)
             compiled = lowered.compile()
         ca = compiled.cost_analysis()
-        if isinstance(ca, list):           # jax <= 0.4.x: list of dicts
-            ca = ca[0] if ca else {}
         coll = collective_bytes(compiled.as_text())
         return {
             "flops": float(ca.get("flops", 0.0)),

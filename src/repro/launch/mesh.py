@@ -7,28 +7,8 @@ sets ``xla_force_host_platform_device_count``.
 
 from __future__ import annotations
 
-import math
-
 import jax
-import numpy as np
-from jax.sharding import Mesh
-
-try:                                   # jax >= 0.5: explicit-axis-type API
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: only Auto axes exist
-    AxisType = None
-
-
-def compat_mesh(shape, axes) -> Mesh:
-    """make_mesh across jax versions: pass axis_types where supported,
-    fall back to positional construction on older jax."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    devices = np.asarray(jax.devices()[:math.prod(shape)]).reshape(shape)
-    return Mesh(devices, axis_names=axes)
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -38,7 +18,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     where gradient compression / hierarchical gateways attach."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_sweep_mesh(n_devices: int | None = None) -> Mesh:
@@ -51,8 +32,10 @@ def make_sweep_mesh(n_devices: int | None = None) -> Mesh:
     balancer-replica blocks, so the same mesh also shards the user axis:
     a 10^6-user config becomes ~10^3 block rows spread over the devices,
     per-user state and all."""
-    n = len(jax.devices()) if n_devices is None else n_devices
-    return compat_mesh((n,), ("config",))
+    devices = jax.devices()
+    n = len(devices) if n_devices is None else n_devices
+    return jax.make_mesh((n,), ("config",), axis_types=(AxisType.Auto,),
+                         devices=devices[:n])
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -60,4 +43,6 @@ def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
     n = len(jax.devices())
     if data * model > n:
         data, model = n, 1
-    return compat_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=jax.devices()[:data * model])

@@ -10,10 +10,12 @@ import argparse
 import json
 
 from repro.core.profiles import paper_fleet, synthetic_fleet
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import ServingEngine
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--policy", default="MO")
     ap.add_argument("--gamma", type=float, default=0.5)

@@ -5,6 +5,7 @@ tests see the normal environment)."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from repro.distributed.sharding import DEFAULT_RULES
 def _run_subprocess(body: str) -> str:
     code = textwrap.dedent("""
         import os
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys
         sys.path.insert(0, "src")
@@ -23,7 +25,8 @@ def _run_subprocess(body: str) -> str:
         import numpy as np
     """) + textwrap.dedent(body)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd="/root/repo", timeout=480)
+                         text=True, cwd=Path(__file__).resolve().parents[1],
+                         timeout=480)
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
 
@@ -58,8 +61,8 @@ def test_seq_sharded_decode_matches_reference():
     body = """
         from repro.distributed.collectives import seq_sharded_decode
         from repro.kernels.decode_attention.ref import ref_decode_attention
-        from repro.launch.mesh import compat_mesh
-        mesh = compat_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = jax.random.PRNGKey(0)
         B, S, H, KV, D = 2, 64, 8, 4, 16
         q = jax.random.normal(rng, (B, H, D))
@@ -78,22 +81,19 @@ def test_seq_sharded_decode_matches_reference():
 def test_sharded_train_step_matches_single_device():
     """One reduced LM train step on an 8-device mesh == 1-device result."""
     body = """
-        import contextlib
         from repro import configs as C
         from repro.launch import steps as S
-        from repro.launch.mesh import compat_mesh
         arch = C.get("stablelm-3b")
         shape = arch.shapes[0]
-        mesh = compat_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cell1 = S.build_cell(arch, shape, mesh=None, reduced=True)
         args = S.init_concrete(cell1, jax.random.PRNGKey(0))
         _, m1 = jax.jit(cell1.step_fn)(*args)
 
         cell2 = S.build_cell(arch, shape, mesh=mesh, reduced=True)
         args2 = S.init_concrete(cell2, jax.random.PRNGKey(0))
-        ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") \\
-            else contextlib.nullcontext()
-        with ctx:
+        with jax.set_mesh(mesh):
             _, m2 = jax.jit(cell2.step_fn,
                             in_shardings=cell2.in_shardings(mesh))(*args2)
         a, b = float(m1["loss"]), float(m2["loss"])

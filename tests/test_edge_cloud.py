@@ -3,8 +3,9 @@
 Three layers of protection around ``repro.core.cloud``:
 
   * golden regression — every ``cloud=None`` scenario stays bit-identical
-    to ``tests/golden_cloud_pr7.json`` (captured from the pre-CloudTier
-    engine), on a single device AND a forced 4-device mesh;
+    to ``tests/golden_cloud_pr7.json`` (the pre-CloudTier engine's values;
+    see ``scripts/capture_golden_engine.py``), on a single device AND a
+    forced 4-device mesh;
   * properties — a zero-cost cloud pair (rtt=0, bw=inf, xfer-energy=0)
     scores bitwise like a local pair with the same profile; offload share
     is monotone non-increasing in RTT; CloudTier round-trips through
@@ -116,7 +117,7 @@ print("OK")
 def test_cloud_golden_in_forced_4_device_subprocess():
     """PR 7 golden + cloud-active sharding on a real 4-device mesh
     (xla_force_host_platform_device_count in a fresh process)."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=str(REPO / "src") + os.pathsep
                + os.environ.get("PYTHONPATH", ""))

@@ -3,10 +3,10 @@
 Four layers of protection:
 
   * golden regression — every ``faults=None`` scenario stays bit-identical
-    to ``tests/golden_faults_pr9.json`` (captured from the pre-fault
-    engine), on a single device AND a forced 4-device mesh, cloud-active
-    scenarios included (the fault plane rewires the simulator's uplink
-    branch);
+    to ``tests/golden_faults_pr9.json`` (the pre-fault engine's values;
+    see ``scripts/capture_golden_engine.py``), on a single device AND a
+    forced 4-device mesh, cloud-active scenarios included (the fault plane
+    rewires the simulator's uplink branch);
   * routing properties — no policy ever selects a masked-down pair; the
     degraded fallback is the healthy argmin-latency pair and counts an
     SLO violation; every moscore backend agrees bit-identically under a
@@ -134,7 +134,7 @@ print("OK")
 def test_faults_golden_in_forced_4_device_subprocess():
     """PR 9 golden + fault-active sharding on a real 4-device mesh
     (xla_force_host_platform_device_count in a fresh process)."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=str(REPO / "src") + os.pathsep
                + os.environ.get("PYTHONPATH", ""))

@@ -261,15 +261,19 @@ for k, v in fix["metrics"].items():
         np.testing.assert_array_equal(gold[k], want, err_msg=k)
 
 # multi-block sharded == multi-block single-device, bitwise: block rows
-# ride the sharded config axis (per-user state sharded across devices)
-sc = Scenario(n_users=50, n_requests=100, user_block=8)
-ref = run(sc)
-out = run(sc, mesh="local")
-for k in ref.metric_names:
-    if k == "latency_p90_ms":
-        np.testing.assert_allclose(out[k], ref[k], rtol=3e-7, err_msg=k)
-    else:
-        np.testing.assert_array_equal(out[k], ref[k], err_msg=k)
+# ride the sharded config axis (per-user state sharded across devices);
+# the 500-block grid spans every shard, so its per-config folds would
+# reassociate if they ran over the sharded axis
+for sc, sw in ((Scenario(n_users=50, n_requests=100, user_block=8), None),
+               (Scenario(n_users=2000, n_requests=100, user_block=8),
+                Sweep(policy=("MO", "HA")))):
+    ref = run(sc, sw)
+    out = run(sc, sw, mesh="local")
+    for k in ref.metric_names:
+        if k == "latency_p90_ms":
+            np.testing.assert_allclose(out[k], ref[k], rtol=3e-7, err_msg=k)
+        else:
+            np.testing.assert_array_equal(out[k], ref[k], err_msg=k)
 print("OK")
 """
 
@@ -279,7 +283,7 @@ def test_user_block_bitwise_in_forced_4_device_subprocess():
     xla_force_host_platform_device_count=4 in a fresh process: K=1 golden
     metrics survive a 4-device mesh with user_block set, and a K>1
     sharded run equals its single-device self bit for bit."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=str(REPO / "src") + os.pathsep
                + os.environ.get("PYTHONPATH", ""))

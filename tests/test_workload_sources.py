@@ -3,9 +3,11 @@
 trace path reproduces an independent looped NumPy replay bit for bit, and
 the contract's invariants hold property-based.
 
-The golden fixture (``golden_markov_pr2.json``) was captured from the
-engine at PR 2 (commit 519f2e2), before ``WorkloadSource`` existed — do
-not regenerate it from current code, that would defeat the regression.
+The golden fixture (``golden_markov_pr2.json``) holds the values of the
+engine from before ``WorkloadSource`` existed, recaptured for the
+installed JAX release by ``scripts/capture_golden_engine.py`` from code
+that matched that engine bit for bit — do not regenerate it from the code
+under test, that would defeat the regression.
 """
 
 import json
