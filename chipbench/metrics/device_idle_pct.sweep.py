@@ -1,0 +1,10 @@
+"""Device idle share of the measured window in a sweep cell: 1 minus the
+union of the busiest device's program intervals over the window, from
+the trace."""
+
+
+def read(ctx):
+    red = ctx["reduced"]
+    if not red.devices or red.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - red.busiest().busy_s / red.window_s)

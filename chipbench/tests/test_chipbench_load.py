@@ -1,0 +1,140 @@
+"""Loading cells, configurations, traffic mixes and metrics by name from
+files, a new cell added by files alone, the shape of ``BENCHMARK.json``,
+the peaks table and the command's refusal of a machine without a TPU."""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+from chipbench_testkit import REPO, make_checkout, run_cell
+
+from chipbench import bench, fleets, roofline
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+SPEC = json.loads((REPO / "BENCHMARK.json").read_text())
+
+
+def test_benchmark_json_shape():
+    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert SPEC["paths"] == ["chipbench"]
+    assert 1 <= SPEC["run_seconds"] <= 51
+    names = [m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]]
+    names += [w["name"] for w in SPEC["workloads"]]
+    names += [c["name"] for c in SPEC["configs"]]
+    assert len(names) == len(set(names))
+    assert all(NAME.match(n) for n in names)
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+    assert any(m["name"] == "setup_s" for m in SPEC["end_to_end"])
+    texts = [e["why"] for e in SPEC["workloads"] + SPEC["configs"]]
+    texts += [m["layer"] for m in SPEC["per_layer"]]
+    texts += [c["source"] for c in SPEC["configs"]] + SPEC["command"]
+    assert all(1 <= len(t) <= 200 and "\n" not in t and "\t" not in t
+               for t in texts)
+    assert all(0.01 <= m["bound"] <= 0.25 for m in SPEC["end_to_end"])
+    four = sum(w["chips"] == 4 for w in SPEC["workloads"])
+    assert four <= max(1, len(SPEC["workloads"]) // 2)
+    assert len(json.dumps(SPEC)) < 64 * 1024
+
+
+def test_every_cell_loads_with_its_files():
+    e2e = {m["name"] for m in SPEC["end_to_end"]}
+    for w in SPEC["workloads"]:
+        cell = bench.load_cell(REPO, w["name"])
+        assert cell.chips in (1, 4)
+        assert bench.driver(cell).setup
+        reported = {m["name"] for m in cell.end_to_end}
+        assert "setup_s" in reported and len(reported) >= 2
+        assert cell.per_layer, w["name"]
+        for m in cell.per_layer:
+            assert m["moves"] in reported and m["moves"] in e2e
+            assert bench.metric_reader(cell, m["name"]).read
+        assert set(cell.traffic["limits"])
+        assert cell.config["name"] == w["config"]
+
+
+def test_configs_match_the_program():
+    import jax
+
+    from repro.core.profiles import paper_fleet, synthetic_fleet
+
+    paper = fleets.tables(bench.load_cell(REPO, "paper_fig4_sweep").config)
+    ref = paper_fleet()
+    for k, v in (("T", ref.T), ("E", ref.E), ("mAP", ref.mAP),
+                 ("floor_mw", ref.floor_mw)):
+        np.testing.assert_array_equal(paper[k], np.asarray(v))
+    city = json.loads((REPO / "chipbench" / "configs" / "city_fleet.json")
+                      .read_text())
+    gen = city["generator"]
+    small = dict(city, generator=dict(gen, n_pairs=64))
+    got = fleets.tables(small)
+    want = synthetic_fleet(jax.random.PRNGKey(gen["key"]), 64)
+    for k, v in (("T", want.T), ("E", want.E), ("mAP", want.mAP),
+                 ("floor_mw", want.floor_mw)):
+        np.testing.assert_array_equal(got[k], np.asarray(v))
+
+
+def test_a_new_cell_is_files_and_one_entry(tmp_path):
+    root = make_checkout(tmp_path)
+    b = root / "chipbench"
+    (b / "configs" / "tiny_city2.json").write_text(json.dumps(
+        dict(json.loads((b / "configs" / "tiny_city.json").read_text()),
+             name="tiny_city2", n_streams=1500)))
+    t = json.loads((b / "traffic" / "tiny_static.json").read_text())
+    t["windows_per_call"] = 2
+    (b / "traffic" / "tiny_two.json").write_text(json.dumps(t))
+    (b / "metrics" / "windows_seen.py").write_text(
+        "def read(ctx):\n    return ctx.get('windows')\n")
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "tiny_city2", "source": "x",
+                            "file": "chipbench/configs/tiny_city2.json",
+                            "reduced": []})
+    spec["workloads"].append({"name": "tiny_two", "config": "tiny_city2",
+                              "traffic": "tiny_two", "chips": 1})
+    for m in spec["end_to_end"]:
+        if m["name"] in ("plane_req_per_s", "decision_p90_ms"):
+            m["workloads"].append("tiny_two")
+    spec["per_layer"].append({"name": "windows_seen", "unit": "count",
+                              "better": "higher", "source": "host_clock",
+                              "layer": "x", "moves": "plane_req_per_s",
+                              "workloads": ["tiny_two"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    cell = bench.load_cell(root, "tiny_two")
+    assert cell.config["n_streams"] == 1500
+    assert [m["name"] for m in cell.per_layer] == ["windows_seen"]
+    rc, res, _ = run_cell(root, "tiny_two", seconds=0.5, trace=1)
+    assert rc == 0 and res["correct"]
+    assert res["metrics"]["windows_seen"]["value"] % 2 == 0
+
+
+def test_unknown_device_kind_raises():
+    assert roofline.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError):
+        roofline.peaks("TPU v9 imaginary")
+
+
+def test_roofline_counts():
+    ops, nbytes = roofline.moscore_hoisted_cost(4096, 5, 1000)
+    assert ops == 20 * 4096 * 1024
+    assert nbytes == 4 * (3 * 5 * 1024 + 2 * 1024 + 2 * 4096)
+    pct, bound = roofline.roofline_pct(197e12, 0, 2.0, "TPU v5 lite")
+    assert pct == pytest.approx(50.0) and bound == "compute"
+
+
+def test_no_tpu_exits_nonzero_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, str(REPO / "chipbench" / "run.py"), "--workload",
+         "paper_fig4_sweep", "--seed", "5", "--seconds", "1"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "no TPU" in p.stderr
