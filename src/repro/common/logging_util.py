@@ -19,16 +19,3 @@ def log(event: str, **fields: Any) -> None:
         sys.stdout.write(str(rec) + "\n")
     sys.stdout.flush()
 
-
-class Timer:
-    def __init__(self, name: str):
-        self.name = name
-
-    def __enter__(self):
-        self.t0 = time.time()
-        return self
-
-    def __exit__(self, *exc):
-        self.dt = time.time() - self.t0
-        log("timer", name=self.name, seconds=round(self.dt, 3))
-        return False

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import spans
 from repro.core import simulator as SIM
 from repro.core.cloud import CloudTier
 from repro.core.faults import FaultSchedule
@@ -669,7 +670,17 @@ def run(scenario: Scenario, sweep: Sweep | None = None, *,
     Returns a :class:`Results`; with no sweep the metric arrays are 0-d
     (``Results.scalar``). A stacked-profile scenario adds a leading
     ``fleet`` axis.
+
+    While a profile is being captured the call is one
+    ``repro.scenario.run`` span; per outer combination it holds
+    ``build_grid``, ``launch``, ``fold`` (user-blocked grids) and
+    ``fetch`` spans (:mod:`repro.common.spans`, ``docs/sweep_engine.md``).
     """
+    with spans.span("repro.scenario.run"):
+        return _run(scenario, sweep, mesh)
+
+
+def _run(scenario: Scenario, sweep: Sweep | None, mesh) -> Results:
     sweep = sweep if sweep is not None else Sweep()
     mesh_obj = _resolve_mesh(scenario.mesh if mesh is None else mesh)
 
@@ -748,83 +759,93 @@ def run(scenario: Scenario, sweep: Sweep | None = None, *,
         n_requests = sc.n_requests
         warmup = int(n_requests * sc.warmup_frac)
 
-        base = dict(n_users=sc.n_users, n_requests=n_requests,
-                    policy=sc.policy, gamma=sc.gamma, delta=sc.delta,
-                    stickiness=sc.stickiness, seed=sc.seed,
-                    warmup_frac=sc.warmup_frac,
-                    oracle_estimator=sc.oracle_estimator)
-        cfgs = [SIM.SimConfig(**{**base, **dict(zip(config_names, vals))})
-                for vals in itertools.product(
-                    *(v for _, v in config_axes))]
-        if sc.user_block is None:
-            grid, segments = SIM._make_grid(prof, cfgs,
-                                            workload=workload), None
-        else:
-            # user-blocked grid: each config's balancer-replica blocks
-            # are extra rows on the config axis (vmapped/sharded as
-            # usual), segment-reduced back to per-config metrics below
-            grid, segments = SIM._make_user_grid(prof, cfgs,
-                                                 sc.user_block,
-                                                 workload=workload)
+        with spans.span("repro.scenario.build_grid"):
+            base = dict(n_users=sc.n_users, n_requests=n_requests,
+                        policy=sc.policy, gamma=sc.gamma, delta=sc.delta,
+                        stickiness=sc.stickiness, seed=sc.seed,
+                        warmup_frac=sc.warmup_frac,
+                        oracle_estimator=sc.oracle_estimator)
+            cfgs = [SIM.SimConfig(**{**base,
+                                     **dict(zip(config_names, vals))})
+                    for vals in itertools.product(
+                        *(v for _, v in config_axes))]
+            if sc.user_block is None:
+                grid, segments = SIM._make_grid(prof, cfgs,
+                                                workload=workload), None
+            else:
+                # user-blocked grid: each config's balancer-replica
+                # blocks are extra rows on the config axis (vmapped/
+                # sharded as usual), segment-reduced back to per-config
+                # metrics below
+                grid, segments = SIM._make_user_grid(prof, cfgs,
+                                                     sc.user_block,
+                                                     workload=workload)
 
-        if drift_axis is not None:
-            out = _drift_axis_fused(prof, workload, dispatch,
-                                    drift_axis[2], cloud_meta, fault_meta,
-                                    grid, n_requests=n_requests,
-                                    warmup=warmup)
-        else:
-            with_hist = segments is not None \
-                and int(np.asarray(segments).shape[0]) > len(cfgs)
-            out = SIM._sweep_summaries(prof, workload, dispatch, drift,
-                                       cloud_meta, fault_meta, grid,
-                                       n_requests=n_requests,
-                                       warmup=warmup, mesh=mesh_obj,
-                                       with_hist=with_hist)
+        with spans.span("repro.scenario.launch"):
+            if drift_axis is not None:
+                out = _drift_axis_fused(prof, workload, dispatch,
+                                        drift_axis[2], cloud_meta,
+                                        fault_meta, grid,
+                                        n_requests=n_requests,
+                                        warmup=warmup)
+            else:
+                with_hist = segments is not None \
+                    and int(np.asarray(segments).shape[0]) > len(cfgs)
+                out = SIM._sweep_summaries(prof, workload, dispatch, drift,
+                                           cloud_meta, fault_meta, grid,
+                                           n_requests=n_requests,
+                                           warmup=warmup, mesh=mesh_obj,
+                                           with_hist=with_hist)
         if segments is not None:
-            out = SIM.aggregate_block_summaries(out, segments, len(cfgs),
-                                                block_axis=-1)
-        if any_cloud and "offload_share" not in out:
-            out = dict(out)
-            out["offload_share"] = jnp.zeros_like(out["latency_ms"])
-        if any_faults and "slo_violation_share" not in out:
-            out = dict(out)
-            for m in ("slo_violation_share", "failed_share",
-                      "latency_p99_ms"):
-                out[m] = jnp.zeros_like(out["latency_ms"])
+            with spans.span("repro.scenario.fold"):
+                out = SIM.aggregate_block_summaries(out, segments,
+                                                    len(cfgs),
+                                                    block_axis=-1)
 
-        block_shape = ((len(drift_axis[1]),) if drift_axis else ()) \
-            + ((prof.n_fleets,) if prof.is_stacked else ()) \
-            + tuple(config_dims)
-        if metrics is None:
-            metrics = {k: np.empty(tuple(outer_dims) + block_shape,
-                                   np.float64) for k in out}
-        oidx = np.unravel_index(oi, tuple(outer_dims)) if outer_axes \
-            else ()
-        for k, v in out.items():
-            metrics[k][oidx] = np.asarray(
-                v, np.float64).reshape(block_shape)
+        with spans.span("repro.scenario.fetch"):
+            if any_cloud and "offload_share" not in out:
+                out = dict(out)
+                out["offload_share"] = jnp.zeros_like(out["latency_ms"])
+            if any_faults and "slo_violation_share" not in out:
+                out = dict(out)
+                for m in ("slo_violation_share", "failed_share",
+                          "latency_p99_ms"):
+                    out[m] = jnp.zeros_like(out["latency_ms"])
 
-    # internal layout -> declared axis order
-    fleet_name = ("profile" if profile_axis
-                  else ("fleet" if implicit_fleet else None))
-    internal = list(outer_names) \
-        + (["drift"] if drift_axis else []) \
-        + ([fleet_name] if fleet_name else []) \
-        + config_names
-    final = (["fleet"] if implicit_fleet else []) + list(sweep.names)
-    perm = [internal.index(n) for n in final]
-    assert metrics is not None
-    # (np.ascontiguousarray would promote 0-d results to 1-d; copy() keeps
-    # the transposed layout materialized without changing rank)
-    metrics = {k: np.transpose(v, perm).copy() for k, v in metrics.items()}
+            block_shape = ((len(drift_axis[1]),) if drift_axis else ()) \
+                + ((prof.n_fleets,) if prof.is_stacked else ()) \
+                + tuple(config_dims)
+            if metrics is None:
+                metrics = {k: np.empty(tuple(outer_dims) + block_shape,
+                                       np.float64) for k in out}
+            oidx = np.unravel_index(oi, tuple(outer_dims)) if outer_axes \
+                else ()
+            for k, v in out.items():
+                metrics[k][oidx] = np.asarray(
+                    v, np.float64).reshape(block_shape)
 
-    coords: dict[str, tuple] = {}
-    if implicit_fleet:
-        coords["fleet"] = tuple(range(base_prof.n_fleets))
-    for n, v in sweep.axes:
-        coords[n] = v
-    return Results(axes=tuple(final), coords=coords, metrics=metrics,
-                   scenario=scenario, sweep=sweep)
+    with spans.span("repro.scenario.fetch"):
+        # internal layout -> declared axis order
+        fleet_name = ("profile" if profile_axis
+                      else ("fleet" if implicit_fleet else None))
+        internal = list(outer_names) \
+            + (["drift"] if drift_axis else []) \
+            + ([fleet_name] if fleet_name else []) \
+            + config_names
+        final = (["fleet"] if implicit_fleet else []) + list(sweep.names)
+        perm = [internal.index(n) for n in final]
+        assert metrics is not None
+        # (np.ascontiguousarray would promote 0-d results to 1-d; copy() keeps
+        # the transposed layout materialized without changing rank)
+        metrics = {k: np.transpose(v, perm).copy() for k, v in metrics.items()}
+
+        coords: dict[str, tuple] = {}
+        if implicit_fleet:
+            coords["fleet"] = tuple(range(base_prof.n_fleets))
+        for n, v in sweep.axes:
+            coords[n] = v
+        return Results(axes=tuple(final), coords=coords, metrics=metrics,
+                       scenario=scenario, sweep=sweep)
 
 
 def records(scenario: Scenario, sweep: Sweep | None = None):

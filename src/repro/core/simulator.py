@@ -92,6 +92,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
+from repro.common import spans
 from repro.core import estimator as EST
 from repro.core.dispatch import (DispatchEngine, DriftSchedule,
                                  default_dispatch)
@@ -770,7 +771,8 @@ def _sweep_summaries(prof, workload, dispatch, drift, cloud, faults,
     # gathered onto one device: a later reduction over the config axis
     # (the user-block segment folds) would otherwise be partitioned over
     # the shards and reassociated, moving means by an ULP
-    out = jax.device_put(out, jax.devices()[0])
+    with spans.span("repro.sweep.gather"):
+        out = jax.device_put(out, jax.devices()[0])
     return {k: (v[..., :n, :] if k == "latency_hist" else v[..., :n])
             for k, v in out.items()}
 

@@ -23,11 +23,11 @@ Two drivers share the stack:
 from __future__ import annotations
 
 import heapq
-import time
 from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.common import spans
 from repro.core import estimator as EST
 from repro.core.profiles import ProfileTable
 from repro.data.workload import VideoStreamWorkload
@@ -149,17 +149,27 @@ class ServingPlane:
         ``router_s`` (total wall-clock inside ``route_window``) and
         ``router_window_s`` (per-window wall-clock samples). Repeated
         calls CONTINUE the plane — clock, streams, queues and belief
-        state persist — so drift can be injected between runs."""
+        state persist — so drift can be injected between runs.
+
+        While a profile is being captured each admission window is a
+        ``repro.plane.window`` span holding ``faults``, ``poll``,
+        ``observe``, ``admit``, ``route`` and ``submit`` spans and the
+        window's counters (:mod:`repro.common.spans`, ``docs/serving.md``).
+        """
         G = self.gateway.prof.n_groups
         meta = self.gateway._fault_meta
         if getattr(self, "_rng", None) is None:     # first run: cold plane
             self._rng = np.random.default_rng(self.seed)
             P_mat = np.asarray(EST.markov_transition(G, self.stickiness))
             self._cumP = P_mat.cumsum(axis=1)
+            # a float32 row sum can end below 1 (0.99999994 on a TPU):
+            # a uniform above it would step to group G, out of range
+            self._cumP[:, -1] = 1.0
             self._scene = self._rng.choice(
                 G, self.n_streams, p=np.asarray(EST.stationary(P_mat)))
             self._now = 0.0
             self._served = 0
+            self._windows = 0           # admission windows so far
             self._retryq = []           # [(rid, stream, g_true), ...]
             self._attempts = {}         # rid -> submissions so far
             self._first_arrival = {}    # rid -> first arrival_s
@@ -175,46 +185,62 @@ class ServingPlane:
             else float(self.gateway.faults.timeout_ms) / 1000.0
         now, done = self._now, 0
         while done < n_requests or self._retryq:
-            if meta is not None:
-                step0 = self.gateway._step
-                if meta.has_down:
-                    down = np.asarray(meta.down_at(step0))
-                    self._requeue(self.pool.fail_pairs(
-                        down, now, timeout_s=timeout_s))
-                if meta.has_throttle:
-                    t_m, e_m = meta.throttle_at(step0)
-                    self.pool.set_fault_throttle(
-                        np.asarray(t_m)[:, None], np.asarray(e_m)[:, None])
-            self._observe(self.pool.poll(now), rng)
-            # admission: queued retries drain at the head of the window
-            # (re-routed against the CURRENT health mask), new streams
-            # fill the rest
-            retry = self._retryq[:self.window]
-            del self._retryq[:len(retry)]
-            w_new = min(self.window - len(retry), n_requests - done)
             rid0 = self._served + done
-            new_streams = np.arange(rid0, rid0 + w_new) % self.n_streams
-            scene[new_streams] = (rng.random((w_new, 1))
-                                  > cumP[scene[new_streams]]).sum(axis=1)
-            streams = np.concatenate(
-                [np.asarray([s for _, s, _ in retry], np.int64),
-                 new_streams])
-            rids = np.concatenate(
-                [np.asarray([r for r, _, _ in retry], np.int64),
-                 np.arange(rid0, rid0 + w_new)])
-            groups = np.concatenate(
-                [np.asarray([g for _, _, g in retry], np.int64),
-                 scene[new_streams]])
-            t0 = time.perf_counter()
-            pairs, gs, _q = self.gateway.route_window(streams,
-                                                      self.pool.depths())
-            pairs = np.asarray(pairs)
-            router_win.append(time.perf_counter() - t0)
-            self.pool.submit_window(pairs, groups, now,
-                                    est_groups=np.asarray(gs),
-                                    stream_ids=streams, rids=rids)
+            with spans.span("repro.plane.window", window=self._windows,
+                            rid0=rid0):
+                if meta is not None:
+                    with spans.span("repro.plane.faults"):
+                        step0 = self.gateway._step
+                        if meta.has_down:
+                            down = np.asarray(meta.down_at(step0))
+                            self._requeue(self.pool.fail_pairs(
+                                down, now, timeout_s=timeout_s))
+                        if meta.has_throttle:
+                            t_m, e_m = meta.throttle_at(step0)
+                            self.pool.set_fault_throttle(
+                                np.asarray(t_m)[:, None],
+                                np.asarray(e_m)[:, None])
+                with spans.span("repro.plane.poll"):
+                    resp = self.pool.poll(now)
+                with spans.span("repro.plane.observe"):
+                    self._observe(resp, rng)
+                with spans.span("repro.plane.admit"):
+                    # queued retries drain at the head of the window
+                    # (re-routed against the CURRENT health mask), new
+                    # streams fill the rest
+                    retry = self._retryq[:self.window]
+                    del self._retryq[:len(retry)]
+                    w_new = min(self.window - len(retry), n_requests - done)
+                    new_streams = np.arange(rid0, rid0 + w_new) \
+                        % self.n_streams
+                    scene[new_streams] = (rng.random((w_new, 1))
+                                          > cumP[scene[new_streams]]
+                                          ).sum(axis=1)
+                    streams = np.concatenate(
+                        [np.asarray([s for _, s, _ in retry], np.int64),
+                         new_streams])
+                    rids = np.concatenate(
+                        [np.asarray([r for r, _, _ in retry], np.int64),
+                         np.arange(rid0, rid0 + w_new)])
+                    groups = np.concatenate(
+                        [np.asarray([g for _, _, g in retry], np.int64),
+                         scene[new_streams]])
+                with spans.timed("repro.plane.route") as route:
+                    pairs, gs, _q = self.gateway.route_window(
+                        streams, self.pool.depths())
+                    pairs = np.asarray(pairs)
+                router_win.append(route.seconds)
+                with spans.span("repro.plane.submit"):
+                    self.pool.submit_window(pairs, groups, now,
+                                            est_groups=np.asarray(gs),
+                                            stream_ids=streams, rids=rids)
+                if spans.collecting():
+                    spans.count("plane.admitted", w_new)
+                    spans.count("plane.retried", len(retry))
+                    spans.count("pool.in_flight", self.pool.in_flight)
             now += streams.shape[0] / rps
             done += w_new
+            self._windows += 1
         self._observe(self.pool.poll(np.inf), rng)   # drain the tail
         self._now = max(now, float(self.pool._avail.max(initial=0.0)))
         self._served += done

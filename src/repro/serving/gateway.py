@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import spans
 from repro.core import estimator as EST
 from repro.core.dispatch import (DispatchEngine, OnlineDispatch,
                                  StaticDispatch)
@@ -213,8 +214,12 @@ class WindowedGateway:
         penalty_fn = None if cloud_meta is None else cloud_meta.penalty
         fault_meta = self._fault_meta
 
+        # each program below counts its traces as ``gateway.retrace``
+        # (``fn=`` its name) while a profile is being captured, so a
+        # trace says which one recompiled
         @jax.jit
         def _route_fused(state, counts, q0, ids):
+            spans.count("gateway.retrace", fn="_route_fused")
             # MO fast path: estimator gather + the fused routing kernel
             # against the engine's current belief tables; rr advances by
             # W exactly as W select() calls would have advanced it
@@ -228,6 +233,7 @@ class WindowedGateway:
 
         @jax.jit
         def _route_scan(state, counts, q0, ids, step0):
+            spans.count("gateway.retrace", fn="_route_scan")
             # generic path (every policy): the engine's select_window
             # scan, with per-request keys folded from the ABSOLUTE
             # request index — window-partition invariant
@@ -242,6 +248,7 @@ class WindowedGateway:
 
         @jax.jit
         def _route_scan_masked(state, counts, q0, ids, step0):
+            spans.count("gateway.retrace", fn="_route_scan_masked")
             # fault-visible path: the same scan, plus the per-request
             # health mask drawn from the ABSOLUTE request index — fault
             # realizations are window-partition invariant exactly like
@@ -258,6 +265,7 @@ class WindowedGateway:
 
         @jax.jit
         def _route_pods(state, counts, q0, ids):
+            spans.count("gateway.retrace", fn="_route_pods")
             # hierarchical path: pod queue totals are snapshotted ONCE at
             # window admission (stale inside the window); level-2 exact
             # queues get in-window feedback like every other path
@@ -282,6 +290,7 @@ class WindowedGateway:
 
         @jax.jit
         def _obs_counts(counts, ids, cnts):
+            spans.count("gateway.retrace", fn="_obs_counts")
             # last-write-wins scatter: scatter-MAX of the window index
             # per stream is well-defined under duplicates (unlike
             # .at[].set), so the result is bit-identical to a sequential
@@ -294,11 +303,13 @@ class WindowedGateway:
 
         @jax.jit
         def _observe_win(state, pairs, groups, t_ms, e_mwh):
+            spans.count("gateway.retrace", fn="_observe_win")
             return engine.observe_window(state, pairs, groups, t_ms,
                                          e_mwh)
 
         @jax.jit
         def _observe_one(state, p, g, t_ms, e_mwh):
+            spans.count("gateway.retrace", fn="_observe_one")
             return engine.observe(state, p, g, t_ms, e_mwh)
 
         self._route_fused = _route_fused

@@ -24,7 +24,7 @@ def _fenced_python(md: Path) -> list[str]:
 EMBEDDED_EXAMPLES = {
     "sweep_engine.md": ["scenario_api.py", "trace_workload.py",
                         "online_drift.py", "sweep_quickstart.py",
-                        "user_scaling.py", "edge_cloud.py"],
+                        "user_scaling.py", "edge_cloud.py", "tracing.py"],
     "serving.md": ["serving_gateway.py"],
     "kernels.md": ["moscore_backends.py"],
     "resilience.md": ["fault_injection.py"],
