@@ -789,13 +789,16 @@ def _run(scenario: Scenario, sweep: Sweep | None, mesh) -> Results:
                                         n_requests=n_requests,
                                         warmup=warmup)
             else:
-                with_hist = segments is not None \
+                # multi-block configs merge their rows' latency
+                # histograms per config inside the device program
+                multi = segments is not None \
                     and int(np.asarray(segments).shape[0]) > len(cfgs)
                 out = SIM._sweep_summaries(prof, workload, dispatch, drift,
                                            cloud_meta, fault_meta, grid,
                                            n_requests=n_requests,
                                            warmup=warmup, mesh=mesh_obj,
-                                           with_hist=with_hist)
+                                           segments=segments if multi
+                                           else None)
         if segments is not None:
             with spans.span("repro.scenario.fold"):
                 out = SIM.aggregate_block_summaries(out, segments,

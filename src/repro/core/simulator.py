@@ -99,7 +99,8 @@ from repro.core.dispatch import (DispatchEngine, DriftSchedule,
 from repro.core.policies import POLICY_CODES
 from repro.core.profiles import ProfileTable
 from repro.core.useraxis import (aggregate_block_summaries, block_segments,
-                                 block_sizes, latency_histogram)
+                                 block_sizes, latency_histogram,
+                                 segment_user_sum)
 from repro.core.workload import (MarkovWorkload, WorkloadSource,
                                  _init_draws, default_workload,
                                  grid_cache_clear, grid_cache_info)
@@ -439,12 +440,13 @@ def _sweep_user_summaries(prof, workload, dispatch, drift, cloud, faults,
     state rides the sharded config axis), then segment-reduce back to
     per-config metrics on device. Single-block configs pass through the
     aggregation bit-identically; multi-block configs additionally carry
-    the per-block latency histogram so the fleet-wide p90 is an exact
-    merge, not a mean of per-block percentiles."""
+    the latency histogram, merged per config inside the device program,
+    so the fleet-wide p90 is an exact merge, not a mean of per-block
+    percentiles."""
     multi = int(np.asarray(segments).shape[0]) > n_cfgs
     out = _sweep_summaries(prof, workload, dispatch, drift, cloud, faults,
                            grid, n_requests=n_requests, warmup=warmup,
-                           mesh=mesh, with_hist=multi)
+                           mesh=mesh, segments=segments if multi else None)
     return aggregate_block_summaries(out, segments, n_cfgs, block_axis=-1)
 
 
@@ -674,15 +676,21 @@ def _simulate_vmapped(prof, workload, dispatch, drift, cloud, faults,
 
 
 def _fused_summaries(prof, workload, dispatch, drift, cloud, faults,
-                     grid: ConfigGrid, *, n_requests: int, warmup: int,
-                     with_hist: bool = False):
+                     grid: ConfigGrid, segments=None, *, n_requests: int,
+                     warmup: int, num_configs: int = 0):
     """The simulate + summarize composition over (fleet,) config — the ONE
     source of truth shared by the single-device jit and the shard_map'ed
     path, so the two can never drift apart and break the bit-identical
     guarantee. Returns (B,) metric vectors — (F, B) for a stacked fleet —
-    without materialising (B, N) records. ``with_hist`` additionally
-    emits the fixed-bin latency histogram leaf (``(B, NB)``) the
-    user-block aggregation merges into exact fleet-wide percentiles."""
+    without materialising (B, N) records.
+
+    ``segments`` ((B,) int32, one config id per grid row) additionally
+    emits the ``latency_hist`` leaf, ``(num_configs, NB)`` — ``(F,
+    num_configs, NB)`` for a stacked fleet: each row's fixed-bin latency
+    histogram, segment-summed into its config's. Rows with id
+    ``num_configs`` (padding) are dropped. The counts are integer-valued
+    float32, so the merge is exact in any grouping up to 2^24 per bin."""
+    with_hist = segments is not None
 
     def per_fleet(pf):
         def one(g):
@@ -691,89 +699,108 @@ def _fused_summaries(prof, workload, dispatch, drift, cloud, faults,
             return _summarize_core(recs, pf, warmup, cloud,
                                    with_hist=with_hist)
 
-        return jax.vmap(one)(grid)
+        out = jax.vmap(one)(grid)
+        if with_hist:
+            out["latency_hist"] = segment_user_sum(
+                out["latency_hist"], segments, num_configs)
+        return out
 
     return _over_fleet(per_fleet, prof)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_requests", "warmup", "with_hist"))
+                   static_argnames=("n_requests", "warmup", "num_configs"))
 def _sweep_fused(prof, workload, dispatch, drift, cloud, faults,
-                 grid: ConfigGrid, *, n_requests: int, warmup: int,
-                 with_hist: bool = False):
+                 grid: ConfigGrid, segments=None, *, n_requests: int,
+                 warmup: int, num_configs: int = 0):
     return _fused_summaries(prof, workload, dispatch, drift, cloud, faults,
-                            grid, n_requests=n_requests, warmup=warmup,
-                            with_hist=with_hist)
+                            grid, segments, n_requests=n_requests,
+                            warmup=warmup, num_configs=num_configs)
 
 
 @functools.lru_cache(maxsize=None)
 def _sweep_sharded_fn(mesh: Mesh, n_requests: int, warmup: int,
-                      stacked: bool, with_hist: bool = False):
+                      stacked: bool, num_configs: int = 0):
     """Build (and cache per mesh/shape signature) the shard_map'ed fused
     sweep: the config axis is split over every mesh axis, the profile
     table, workload source, dispatch engine, drift schedule and cloud
     meta are replicated, and each shard runs the plain vmapped simulate +
-    summarize — no collectives, the grid is embarrassingly parallel. The
-    inner jit re-specialises per workload/dispatch/drift/cloud pytree
-    structure, so one cache entry serves Markov and trace runs, static
-    and online engines, edge-only and edge+cloud fleets."""
+    summarize. The metric leaves stay config-sharded. With
+    ``num_configs`` set the program also takes the rows' config ids
+    (sharded like the grid): each shard merges the latency histograms of
+    the rows it holds into per-config partials, and one ``psum`` over
+    the mesh adds them (integer counts, exact), so the ``(C, NB)``
+    histogram comes out replicated and no per-row histogram leaves a
+    shard. The inner jit re-specialises per workload/dispatch/drift/
+    cloud pytree structure, so one cache entry serves Markov and trace
+    runs, static and online engines, edge-only and edge+cloud fleets."""
     cspec = config_axis_spec(mesh)
     out_spec = PartitionSpec(None, *cspec) if stacked else cspec
-    if with_hist:
-        # every metric leaf is (B,) except the (B, NB) histogram: give
-        # the tree a per-leaf spec so the bin axis stays unsharded
-        def out_spec_of(k, base):
-            return PartitionSpec(*base, None) if k == "latency_hist" \
-                else base
-    else:
-        def out_spec_of(k, base):
-            return base
+    with_hist = num_configs > 0
 
-    def inner(pf, wl, de, dr, cl, fl, g):
-        return _fused_summaries(pf, wl, de, dr, cl, fl, g,
-                                n_requests=n_requests, warmup=warmup,
-                                with_hist=with_hist)
+    fused = functools.partial(_fused_summaries, n_requests=n_requests,
+                              warmup=warmup, num_configs=num_configs)
 
-    def fn(pf, wl, de, dr, cl, fl, g):
-        keys = jax.eval_shape(inner, pf, wl, de, dr, cl, fl, g).keys()
-        specs = {k: out_spec_of(k, out_spec) for k in keys}
-        # check_vma=False: the shards run no collectives, and the
-        # policy lax.switch mixes branches that read config-sharded
-        # state with branches that read only replicated inputs, whose
-        # output types the varying-axes check refuses to unify
+    def inner(*args):
+        out = fused(*args)
+        if with_hist:
+            out["latency_hist"] = jax.lax.psum(out["latency_hist"],
+                                               mesh.axis_names)
+        return out
+
+    def fn(pf, wl, de, dr, cl, fl, g, seg):
+        keys = jax.eval_shape(fused, pf, wl, de, dr, cl, fl, g, seg).keys()
+        specs = {k: PartitionSpec() if k == "latency_hist" else out_spec
+                 for k in keys}
+        # check_vma=False: the policy lax.switch mixes branches that
+        # read config-sharded state with branches that read only
+        # replicated inputs, whose output types the varying-axes check
+        # refuses to unify
         return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(PartitionSpec(), PartitionSpec(), PartitionSpec(),
                       PartitionSpec(), PartitionSpec(), PartitionSpec(),
-                      cspec),
-            out_specs=specs, check_vma=False)(pf, wl, de, dr, cl, fl, g)
+                      cspec, cspec if with_hist else PartitionSpec()),
+            out_specs=specs, check_vma=False)(pf, wl, de, dr, cl, fl, g,
+                                              seg)
 
     return jax.jit(fn)
 
 
 def _sweep_summaries(prof, workload, dispatch, drift, cloud, faults,
                      grid: ConfigGrid, *, n_requests: int, warmup: int,
-                     mesh: Mesh | None, with_hist: bool = False):
+                     mesh: Mesh | None, segments=None):
     """Dispatch a fused sweep to the single-device or sharded path; both
-    return per-config summary dicts with config as the trailing axis of
-    each (B,) / (F, B) leaf — (..., B, NB) for the optional histogram —
-    bit-identical to each other."""
+    return per-row summary dicts with the grid row as the trailing axis
+    of each (B,) / (F, B) leaf, bit-identical to each other.
+
+    ``segments`` (one config id per grid row, ids ``0 .. C-1`` each
+    present) adds the ``latency_hist`` leaf, ``(..., C, NB)``: the rows'
+    latency histograms merged per config inside the device program."""
+    seg = None if segments is None else np.asarray(segments, np.int32)
+    nc = 0 if seg is None else int(seg.max()) + 1
     if mesh is None:
         return _sweep_fused(prof, workload, dispatch, drift, cloud, faults,
-                            grid, n_requests=n_requests, warmup=warmup,
-                            with_hist=with_hist)
+                            grid, None if seg is None else jnp.asarray(seg),
+                            n_requests=n_requests, warmup=warmup,
+                            num_configs=nc)
     n_dev = int(mesh.devices.size)
     padded, n = pad_leading(grid, n_dev)
-    fn = _sweep_sharded_fn(mesh, n_requests, warmup, prof.is_stacked,
-                           with_hist)
+    if seg is not None:
+        # padded rows get id C, which the merge drops
+        seg = np.concatenate([seg, np.full((-n) % n_dev, nc, np.int32)])
+    fn = _sweep_sharded_fn(mesh, n_requests, warmup, prof.is_stacked, nc)
     out = fn(prof, workload, dispatch, drift, cloud, faults,
-             ConfigGrid(*map(jnp.asarray, padded)))
+             ConfigGrid(*map(jnp.asarray, padded)),
+             None if seg is None else jnp.asarray(seg))
     # gathered onto one device: a later reduction over the config axis
     # (the user-block segment folds) would otherwise be partitioned over
     # the shards and reassociated, moving means by an ULP
     with spans.span("repro.sweep.gather"):
+        spans.count("sweep.gather_bytes",
+                    sum(v.nbytes for v in out.values()))
         out = jax.device_put(out, jax.devices()[0])
-    return {k: (v[..., :n, :] if k == "latency_hist" else v[..., :n])
+    return {k: v if k == "latency_hist" else v[..., :n]
             for k, v in out.items()}
 
 

@@ -41,13 +41,18 @@ running *concurrently*, each over the same scan length, so
   * ``latency_p90_ms`` is the **exact fleet-wide percentile of the merged
     latency histogram**: each block row emits a fixed-bin log-spaced
     histogram (:func:`latency_histogram`; counts are integer-valued
-    float32, exact under addition to 2^24), the block histograms
-    segment-sum into the config's pooled histogram, and
+    float32, exact under addition up to 2^24 per bin), the block
+    histograms segment-sum into the config's pooled histogram inside
+    the device program (on a mesh each device merges the rows it holds
+    and a ``psum`` adds the partials, so the histogram arrives per
+    config and no per-block histogram leaves a device; see
+    ``repro.core.simulator._fused_summaries``), and
     :func:`histogram_p90` interpolates the percentile from the pooled
     counts. Because histogram merging is exact, the K-block aggregate is
     bit-identical to running the same estimator on the pooled dense
-    latency set — partition-invariant by construction, with quantization
-    bounded by the bin resolution (~0.5% relative at 4096 log bins over
+    latency set — partition-invariant by construction, and the same
+    bits whichever device holds which block — with quantization bounded
+    by the bin resolution (~0.5% relative at 4096 log bins over
     [1e-5, 1e4] s). Single-block configs keep the exact
     ``jnp.percentile`` passthrough (the golden fixtures pin it).
 """
@@ -187,9 +192,11 @@ def _hist_edges():
 def latency_histogram(latencies):
     """Fixed-bin log-histogram of a latency sample (seconds) -> ``(NB,)``
     float32 counts. Counts are integer-valued float32, so histograms add
-    EXACTLY (up to 2^24 total requests per config) — the property that
-    makes the K-block percentile merge partition-invariant. Out-of-range
-    samples clamp into the edge bins."""
+    EXACTLY in any order and grouping (up to 2^24 requests per bin) —
+    the property that makes the K-block percentile merge
+    partition-invariant, and lets the sweep engine merge a config's
+    block histograms on the devices that hold them. Out-of-range samples
+    clamp into the edge bins."""
     lat = jnp.asarray(latencies, f32).reshape(-1)
     idx = jnp.floor((jnp.log(jnp.maximum(lat, HIST_LO_S)) - _LOG_LO)
                     / _LOG_SPAN * HIST_BINS).astype(i32)
@@ -242,13 +249,14 @@ def aggregate_block_summaries(out: dict, segments, num_configs: int,
     for the exact contract. A config with a single block passes through
     bit-identically.
 
-    When ``out`` carries a ``latency_hist`` leaf (bin axis trailing,
-    block rows at ``block_axis`` counted from the metric leaves — i.e.
-    one axis further in), ``latency_p90_ms`` is recomputed for
-    multi-block configs as the exact percentile of the segment-summed
-    histogram (:func:`histogram_p90`); single-block configs keep their
-    ``jnp.percentile`` value bit-identically. The histogram leaf is
-    consumed, not returned.
+    When ``out`` carries a ``latency_hist`` leaf — the config's merged
+    histogram, laid out as the metric leaves with their block axis cut
+    to ``num_configs`` and the bin axis trailing, as the sweep engine
+    returns it after merging the block rows' histograms on device —
+    ``latency_p90_ms`` is recomputed for multi-block configs as its
+    exact percentile (:func:`histogram_p90`); single-block configs keep
+    their ``jnp.percentile`` value bit-identically. The histogram leaf
+    is consumed, not returned.
     """
     out = dict(out)
     hist = out.pop("latency_hist", None)
@@ -257,11 +265,11 @@ def aggregate_block_summaries(out: dict, segments, num_configs: int,
         # K = 1 everywhere: the expanded grid IS the config grid
         return out
 
-    def lead(v, axis=block_axis):
-        return jnp.moveaxis(jnp.asarray(v), axis, 0)
+    def lead(v):
+        return jnp.moveaxis(jnp.asarray(v), block_axis, 0)
 
-    def unlead(v, axis=block_axis):
-        return jnp.moveaxis(v, 0, axis)
+    def unlead(v):
+        return jnp.moveaxis(v, 0, block_axis)
 
     agg = {}
     for k, v in out.items():
@@ -272,11 +280,7 @@ def aggregate_block_summaries(out: dict, segments, num_configs: int,
         else:
             agg[k] = unlead(segment_user_mean(lead(v), seg, num_configs))
     if hist is not None:
-        # the histogram's block axis sits one slot before its trailing
-        # bin axis relative to the scalar metric leaves
-        haxis = block_axis - 1 if block_axis < 0 else block_axis
-        merged = segment_user_sum(lead(hist, haxis), seg, num_configs)
-        p90_ms = 1000.0 * unlead(histogram_p90(merged), block_axis)
+        p90_ms = 1000.0 * histogram_p90(hist)
         bpc = segment_user_sum(jnp.ones((seg.shape[0],), f32), seg,
                                num_configs)
         agg["latency_p90_ms"] = jnp.where(bpc == 1.0,

@@ -120,7 +120,7 @@ from repro.common import spans
 from repro.core.scenario import Scenario, Sweep, run
 sc = Scenario(n_users=23, user_block=5, n_requests=60, mesh="local")
 sw = Sweep(policy=("MO", "HA"))
-run(sc, sw)
+res = run(sc, sw)
 jax.profiler.start_trace(sys.argv[1])
 try:
     run(sc, sw)
@@ -128,13 +128,17 @@ finally:
     jax.profiler.stop_trace()
 print(json.dumps({"devices": jax.device_count(), "spans": [
     [r.name, r.span_id, r.parent_id] for r in spans.records()
-    if isinstance(r, spans.Span)]}))
+    if isinstance(r, spans.Span)], "counts": [
+    [r.name, r.parent_id, r.n] for r in spans.records()
+    if isinstance(r, spans.Count)], "metrics": len(res.metric_names)}))
 """
 
 
 def test_user_blocked_sharded_run_spans(tmp_path):
     """On 4 forced CPU devices a user-blocked sweep also folds, and the
-    gather onto one device sits inside the launch span."""
+    gather onto one device sits inside the launch span and counts the
+    bytes it moves once: the scalar leaves and the per-config histogram
+    merged on the shards, no per-row histogram."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=str(REPO / "src") + os.pathsep
@@ -155,6 +159,13 @@ def test_user_blocked_sharded_run_spans(tmp_path):
             assert by_id[p][0] == "repro.scenario.launch"
         elif n == "repro.scenario.fold":
             assert by_id[p][0] == "repro.scenario.run"
+    # one gather, of the rows' scalar metrics and the histogram merged
+    # per config on the shards: 2 configs of 5 blocks, padded to 12 rows
+    (name, parent, nbytes), = out["counts"]
+    assert name == "sweep.gather_bytes"
+    assert by_id[parent][0] == "repro.sweep.gather"
+    hist = 2 * 4096 * 4
+    assert hist <= nbytes <= 12 * out["metrics"] * 4 + 4 * hist
 
 
 def test_serving_plane_window_spans(profiling):

@@ -294,6 +294,90 @@ def test_user_block_bitwise_in_forced_4_device_subprocess():
     assert "OK" in res.stdout
 
 
+_SUBPROC_MERGE = """
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import useraxis as UA
+from repro.core.dispatch import StaticDispatch
+from repro.core.profiles import paper_fleet, stack_profiles, synthetic_fleet
+from repro.core.scenario import Scenario, Sweep, run
+from repro.core.simulator import (SimConfig, _make_user_grid,
+                                  _simulate_vmapped, _sweep_summaries)
+from repro.core.workload import MarkovWorkload
+from repro.launch.mesh import make_sweep_mesh
+
+assert len(jax.devices()) == 4, jax.devices()
+mesh = make_sweep_mesh()
+N_REQ, BLOCK, USERS = 60, 4, (28, 20, 33)      # 7, 5 and 9 blocks
+WARMUP = int(N_REQ * 0.1)
+wl, de = MarkovWorkload(), StaticDispatch()
+
+
+def parent_p90(prof, grid, seg, n_cfg):
+    # the merge as a fold after the run: per-row histogram, segment sum
+    # over the block rows, percentile of the merged counts
+    lat = _simulate_vmapped(prof, wl, de, None, None, None, grid,
+                            n_requests=N_REQ)["latency"][..., WARMUP:]
+    rows = jax.vmap(UA.latency_histogram)
+    if prof.is_stacked:
+        rows = jax.vmap(rows)
+    hist = jnp.moveaxis(rows(lat), -2, 0)
+    merged = jnp.moveaxis(UA.segment_user_sum(hist, seg, n_cfg), 0, -2)
+    return 1000.0 * UA.histogram_p90(merged)
+
+
+for prof in (paper_fleet(), stack_profiles(
+        [paper_fleet(), synthetic_fleet(jax.random.PRNGKey(1), 5)])):
+    sc = Scenario(profile=prof, n_requests=N_REQ, user_block=BLOCK)
+    sw = Sweep(n_users=USERS)
+    one = run(sc, sw)
+    four = run(sc, sw, mesh="local")
+    cfgs = [SimConfig(n_users=n, n_requests=N_REQ) for n in USERS]
+    grid, seg = _make_user_grid(prof, cfgs, BLOCK)
+    assert grid.n_configs == 21        # padded to 24 rows over 4 shards
+    want_p90 = np.asarray(parent_p90(prof, grid, seg, len(cfgs)))
+    for k in one.metric_names:
+        np.testing.assert_array_equal(four[k], one[k], err_msg=k)
+    np.testing.assert_array_equal(np.float32(one["latency_p90_ms"]),
+                                  want_p90, err_msg="p90 fold")
+    per_row = _sweep_summaries(prof, wl, de, None, None, None, grid,
+                               n_requests=N_REQ, warmup=WARMUP,
+                               mesh=None)
+    folded = UA.aggregate_block_summaries(per_row, seg, len(cfgs))
+    folded["latency_p90_ms"] = want_p90
+    for k, v in folded.items():
+        np.testing.assert_array_equal(np.float32(one[k]),
+                                      np.asarray(v).reshape(one[k].shape),
+                                      err_msg=f"fold: {k}")
+    # the merged histogram has one row per config, not per block row
+    lead = (prof.n_fleets,) if prof.is_stacked else ()
+    for m in (None, mesh):
+        out = _sweep_summaries(prof, wl, de, None, None, None, grid,
+                               n_requests=N_REQ, warmup=WARMUP, mesh=m,
+                               segments=seg)
+        assert out["latency_hist"].shape == lead + (3, UA.HIST_BINS)
+        assert out["latency_ms"].shape == lead + (21,)
+print("OK")
+"""
+
+
+def test_histogram_merge_on_shards_bitwise_in_forced_4_device_subprocess():
+    """The per-config histogram merge runs inside the device program: on
+    4 forced CPU devices, with configs of 7, 5 and 9 blocks straddling
+    shard boundaries and a grid padded from 21 to 24 rows, the sharded
+    run equals the single-device run and a fold written here (per-row
+    histogram, segment sum, percentile: the merge done after the run)
+    bit for bit over every metric, for one fleet and a stacked pair;
+    ``_sweep_summaries`` returns the histogram with one row per config."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(REPO / "src") + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    res = subprocess.run([sys.executable, "-c", _SUBPROC_MERGE], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stdout + res.stderr
+    assert "OK" in res.stdout
+
+
 def test_k1_sweep_bit_identical_to_unblocked_engine():
     """user_block >= max n_users is a no-op for EVERY metric across a
     mixed sweep, workloads and dispatch engines included."""
@@ -319,10 +403,12 @@ def test_multi_block_equals_manual_per_block_runs():
     wl, de = MarkovWorkload(), StaticDispatch()
     warmup = 12
 
+    # one segment per row: the merge leaves each block's own histogram
     per_block = _sweep_summaries(prof, wl, de, None, None, None, grid,
                                  n_requests=120, warmup=warmup,
-                                 mesh=None, with_hist=True)
+                                 mesh=None, segments=np.arange(3))
     hists = per_block.pop("latency_hist")
+    assert hists.shape == (3, UA.HIST_BINS)
     # each block row == its own single-row run (the engine's vmap
     # invariant, extended to block rows)
     for b in range(3):
