@@ -11,4 +11,4 @@ _p = load_module(Path(__file__).with_name("_program_spans.py"))
 
 
 def read(ctx):
-    return _p.self_ms_per_grid(ctx, "repro.scenario.fold")
+    return _p.self_ms_per(ctx, "repro.scenario.fold", "grid_runs")

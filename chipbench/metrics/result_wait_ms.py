@@ -12,4 +12,4 @@ _p = load_module(Path(__file__).with_name("_program_spans.py"))
 
 
 def read(ctx):
-    return _p.self_ms_per_grid(ctx, "repro.scenario.fetch")
+    return _p.self_ms_per(ctx, "repro.scenario.fetch", "grid_runs")

@@ -12,5 +12,5 @@ _p = load_module(Path(__file__).with_name("_program_spans.py"))
 
 
 def read(ctx):
-    return _p.self_ms_per_grid(ctx, "repro.scenario.launch",
+    return _p.self_ms_per(ctx, "repro.scenario.launch", "grid_runs",
                               own=("repro.sweep.gather",))

@@ -88,8 +88,11 @@ def run(args, *, root: Path = ROOT, require_chip: bool = True,
     _paths()
     import jax
 
-    if cache and not os.environ.get(CACHE_ENV):
-        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    if cache:
+        if not os.environ.get(CACHE_ENV):
+            jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+        # small programs too, so a cell's later runs load every one
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
     from chipbench import bench, trace_reduce
     from chipbench.spans import CompileCounter, Recorder

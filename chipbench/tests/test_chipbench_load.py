@@ -15,10 +15,13 @@ import pytest
 from chipbench_testkit import REPO, make_checkout, run_cell
 
 from chipbench import bench, fleets, roofline
+from chipbench.spans import Recorder
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SPEC = json.loads((REPO / "BENCHMARK.json").read_text())
+#: a tiny cell of the test checkout for each traffic kind
+TINY_OF_KIND = {"served": "tiny_static", "sweep": "tiny_sweep"}
 
 
 def test_benchmark_json_shape():
@@ -61,6 +64,38 @@ def test_every_cell_loads_with_its_files():
         assert cell.config["name"] == w["config"]
 
 
+@pytest.fixture(scope="module")
+def reported_by_kind(tmp_path_factory):
+    """The end-to-end metrics each traffic kind's driver reports, read
+    from a short window of a tiny cell of that kind; ``run.py`` adds
+    ``setup_s``."""
+    root = make_checkout(tmp_path_factory.mktemp("kinds"))
+    out = {}
+    for kind, tiny in TINY_OF_KIND.items():
+        cell = bench.load_cell(root, tiny)
+        drv = bench.driver(cell).setup(cell.config, cell.traffic, 3,
+                                       Recorder())
+        out[kind] = set(drv.window(0.2)) | {"setup_s"}
+    return out
+
+
+@pytest.mark.parametrize("workload", [w["name"] for w in SPEC["workloads"]])
+def test_every_end_to_end_metric_is_one_its_driver_reports(
+        reported_by_kind, workload):
+    cell = bench.load_cell(REPO, workload)
+    given = {m["name"] for m in cell.end_to_end}
+    assert given <= reported_by_kind[cell.traffic["kind"]], workload
+
+
+def test_sweep_cells_report_the_sweep_rate_and_setup_alone():
+    cells = [bench.load_cell(REPO, w["name"]) for w in SPEC["workloads"]]
+    sweeps = [c for c in cells if c.traffic["kind"] == "sweep"]
+    assert sweeps
+    for cell in sweeps:
+        assert {m["name"] for m in cell.end_to_end} == {"sweep_req_per_s",
+                                                        "setup_s"}
+
+
 def test_configs_match_the_program():
     import jax
 
@@ -87,9 +122,9 @@ def test_a_new_cell_is_files_and_one_entry(tmp_path):
     b = root / "chipbench"
     (b / "configs" / "tiny_city2.json").write_text(json.dumps(
         dict(json.loads((b / "configs" / "tiny_city.json").read_text()),
-             name="tiny_city2", n_streams=1500)))
+             name="tiny_city2")))
     t = json.loads((b / "traffic" / "tiny_static.json").read_text())
-    t["windows_per_call"] = 2
+    t.update(windows_per_call=2, n_streams=40)
     (b / "traffic" / "tiny_two.json").write_text(json.dumps(t))
     (b / "metrics" / "windows_seen.py").write_text(
         "def read(ctx):\n    return ctx.get('windows')\n")
@@ -100,7 +135,7 @@ def test_a_new_cell_is_files_and_one_entry(tmp_path):
     spec["workloads"].append({"name": "tiny_two", "config": "tiny_city2",
                               "traffic": "tiny_two", "chips": 1})
     for m in spec["end_to_end"]:
-        if m["name"] in ("plane_req_per_s", "decision_p90_ms"):
+        if m["name"] == "plane_req_per_s":
             m["workloads"].append("tiny_two")
     spec["per_layer"].append({"name": "windows_seen", "unit": "count",
                               "better": "higher", "source": "host_clock",
@@ -108,7 +143,8 @@ def test_a_new_cell_is_files_and_one_entry(tmp_path):
                               "workloads": ["tiny_two"]})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     cell = bench.load_cell(root, "tiny_two")
-    assert cell.config["n_streams"] == 1500
+    assert cell.config["name"] == "tiny_city2"
+    assert cell.traffic["n_streams"] == 40
     assert [m["name"] for m in cell.per_layer] == ["windows_seen"]
     rc, res, _ = run_cell(root, "tiny_two", seconds=0.5, trace=1)
     assert rc == 0 and res["correct"]

@@ -23,8 +23,7 @@ def test_sound_run_is_correct(root, workload):
     rc, res, err = run_cell(root, workload, seed=2 ** 31 + 11)
     assert rc == 0 and res["correct"], err
     assert list(res)[-1] == "checks"
-    assert set(res["metrics"]) == {"plane_req_per_s", "decision_p90_ms",
-                                   "setup_s"}
+    assert set(res["metrics"]) == {"plane_req_per_s", "setup_s"}
     assert res["checks"]["group_mismatch"]["value"] == 0
     assert err.strip().splitlines()[-1].startswith("check ")
 
@@ -36,7 +35,7 @@ def test_rates_are_all_work_over_all_time(root):
     assert out["plane_req_per_s"] == pytest.approx(drv.requests / drv.wall)
     assert drv.requests % (cell.traffic["window"]
                            * cell.traffic["windows_per_call"]) == 0
-    # the tail is over every request of every window of the window
+    # every admission window of the window is counted, and each is full
     routes = [ev for ev in drv.rec.log[drv.log_start:]
               if ev[0] == "gateway.route_window"]
     assert len(routes) == drv.windows
@@ -59,7 +58,7 @@ def _frozen(self, stream_ids, detected_counts):
 
 def _half(orig):
     def half(self, stream_ids, detected_counts):
-        n = len(stream_ids) // 2
+        n = (len(stream_ids) + 1) // 2     # a batch of one stays whole
         return orig(self, np.asarray(stream_ids)[:n],
                     np.asarray(detected_counts)[:n])
     return half
