@@ -48,6 +48,23 @@ def load_module(path: Path) -> ModuleType:
     return mod
 
 
+def load_config(root: Path, configs: dict, name: str) -> dict:
+    """The configuration ``name`` of ``configs`` (``BENCHMARK.json``'s
+    entries by name). One that names another's fleet (``"fleet"``) gets
+    that configuration, loaded, in the name's place."""
+    if name not in configs:
+        raise KeyError(f"no configuration {name!r}; known: "
+                       f"{sorted(configs)}")
+    config = _load_json(root / configs[name]["file"])
+    if "fleet" in config:
+        fleet = load_config(root, configs, config["fleet"])
+        if "fleet" in fleet:
+            raise ValueError(f"configuration {name!r} names the fleet of "
+                             f"{config['fleet']!r}, which names another")
+        config["fleet"] = fleet
+    return config
+
+
 def _applies(metric: dict, cell: str, reported: set) -> bool:
     if "workloads" in metric:
         return cell in metric["workloads"]
@@ -63,7 +80,7 @@ def load_cell(root: Path, workload: str) -> Cell:
         raise KeyError(f"no workload {workload!r}; known: {sorted(cells)}")
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = _load_json(root / configs[w["config"]]["file"])
+    config = load_config(root, configs, w["config"])
     traffic = _load_json(root / BENCH_DIR / "traffic"
                          / f"{w['traffic']}.json")
     e2e = [m for m in bench["end_to_end"]

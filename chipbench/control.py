@@ -5,10 +5,11 @@ and the control's, on many seeds, in one process.
         --seconds <s> [--out <file.jsonl>]
 
 For each seed the cell is built and run for a short window exactly as
-``run.py`` does; then the numbers compared are read twice: as the
-benchmark reads them, and with the reference in the nearest lower
-precision put in the timed path's place (the control). One JSON line per
-seed. The benchmark's own runs never run the control.
+``run.py`` does; then the numbers compared are read as the benchmark
+reads them, and again with each control the driver names
+(``controls()``) in the timed path's place: the reference in the
+nearest lower precision and any other the cell offers. One JSON line
+per seed. The benchmark's own runs never run a control.
 """
 
 from __future__ import annotations
@@ -50,9 +51,12 @@ def main(argv=None) -> int:
         t1 = time.perf_counter()
         program = drv.check()
         t2 = time.perf_counter()
-        control = drv.check(control=True)
+        controls = {f"control_{c}": drv.check(control=c)
+                    for c in drv.controls()}
+        attempted, failed = drv.attempted()
         line = json.dumps({"workload": cell.name, "seed": seed,
-                           "program": program, "control": control,
+                           "program": program, **controls,
+                           "attempted": attempted, "failed": failed,
                            "run_s": t1 - t0, "check_s": t2 - t1,
                            "control_s": time.perf_counter() - t2})
         print(line, flush=True)

@@ -31,6 +31,11 @@ from chipbench.reference import simulator as ref
 #: per-config metrics compared with the reference
 METRICS = ("latency_ms", "latency_p90_ms", "throughput_rps", "energy_mwh",
            "map", "estimator_acc", "makespan_s")
+#: the benchmark's CPU tests run this kind on a small mix: this traffic
+#: file, with these keys set
+TINY_MIX = {"traffic": "sweep_fig4",
+            "set": {"n_users": [1, 5, 15], "n_requests": 300,
+                    "seed_offsets": [0, 1]}}
 
 
 def program_seeds(seed: int, offsets) -> tuple:
@@ -86,10 +91,14 @@ class Sweep:
     def attempted(self) -> tuple[int, int]:
         return self.runs * self.per_grid, 0
 
-    def check(self, control: bool = False) -> dict:
+    def controls(self) -> tuple:
+        """The controls :meth:`check` can put in the program's place."""
+        return ("bfloat16",)
+
+    def check(self, control=None) -> dict:
         """The widest relative gap between the last grid's per-config
-        metrics and the reference's. ``control`` runs the reference in
-        bfloat16 in the program's place."""
+        metrics and the reference's. ``control`` ``"bfloat16"`` runs the
+        reference in bfloat16 in the program's place."""
         import jax.numpy as jnp
 
         tr = self.traffic
@@ -105,7 +114,7 @@ class Sweep:
             ref.simulate(self.tables, rws, n_requests=n_req),
             seg, len(configs), self.tables["floor_mw"],
             warmup=int(n_req * float(tr["warmup_frac"])))
-        if control:
+        if control == "bfloat16":
             got = ref.summaries(
                 ref.simulate(self.tables, rws, n_requests=n_req,
                              dtype=jnp.bfloat16),

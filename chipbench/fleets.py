@@ -2,12 +2,15 @@
 
 A configuration either lists its profile tables (``"tables"``: T in ms,
 E in mWh, mAP in points, per pair and scene group, and each pair's
-active-floor power in mW) or names a generator with its arguments
-(``"generator"``). The generator here is a copy of the repository's
-``synthetic_fleet`` scale-test generator, run as one jitted call on the
-device from the configuration's own key, so the program and the
-reference get the same tables without the reference taking anything the
-program made.
+active-floor power in mW), names a generator with its arguments
+(``"generator"``), or names another configuration whose fleet it runs
+(``"fleet"``: a deployment that adds, say, a fault schedule to a fleet
+the benchmark already holds; ``bench.load_config`` puts that
+configuration in the name's place). The generator here is a copy of the
+repository's ``synthetic_fleet`` scale-test generator, run as one jitted
+call on the device from the configuration's own key, so the program and
+the reference get the same tables without the reference taking anything
+the program made.
 """
 
 from __future__ import annotations
@@ -45,6 +48,11 @@ def _synthetic(key, *, n_pairs: int, n_groups: int, frac_strong: float):
 def tables(config: dict) -> dict:
     """``{"T", "E", "mAP", "floor_mw"}`` float32 NumPy arrays of the
     configuration's fleet."""
+    if "fleet" in config:
+        if isinstance(config["fleet"], str):
+            raise ValueError(f"fleet {config['fleet']!r} is a name: load "
+                             f"the configuration with bench.load_config")
+        return tables(config["fleet"])
     if "tables" in config:
         t = config["tables"]
         return {k: np.asarray(t[k], np.float32)

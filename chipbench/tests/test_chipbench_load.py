@@ -1,6 +1,7 @@
 """Loading cells, configurations, traffic mixes and metrics by name from
-files, a new cell added by files alone, the shape of ``BENCHMARK.json``,
-the peaks table and the command's refusal of a machine without a TPU."""
+files, a new cell and a new traffic kind added by files alone, a
+deployment that names its fleet, the shape of ``BENCHMARK.json``, the
+peaks table and the command's refusal of a machine without a TPU."""
 
 from __future__ import annotations
 
@@ -12,16 +13,57 @@ import sys
 
 import numpy as np
 import pytest
-from chipbench_testkit import REPO, make_checkout, run_cell
+from chipbench_testkit import (REPO, TINY_FAULTS, check_kind, driver_kinds,
+                               make_checkout, run_cell)
 
 from chipbench import bench, fleets, roofline
-from chipbench.spans import Recorder
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SPEC = json.loads((REPO / "BENCHMARK.json").read_text())
-#: a tiny cell of the test checkout for each traffic kind
-TINY_OF_KIND = {"served": "tiny_static", "sweep": "tiny_sweep"}
+#: a traffic kind of its own, as a later change would add it: one driver
+#: file that runs a device program and one traffic file
+ECHO_DRIVER = '''"""Traffic kind ``echo``: sums a vector on the device."""
+import time
+
+import jax.numpy as jnp
+import numpy as np
+
+TINY_MIX = {"traffic": "echo", "set": {"n": 64}}
+
+
+class Echo:
+    def __init__(self, traffic, seed):
+        self.x = np.random.default_rng(seed).random(int(traffic["n"]))
+        self.sums = []
+
+    def window(self, seconds):
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < seconds or not self.sums:
+            self.sums.append(float(jnp.sum(jnp.asarray(self.x))))
+        return {"echo_per_s": len(self.sums) / (time.perf_counter() - t0)}
+
+    def counts(self):
+        return {"calls": len(self.sums)}
+
+    def attempted(self):
+        return len(self.sums), 0
+
+    def controls(self):
+        return ("float16",)
+
+    def check(self, control=None):
+        want = float(np.sum(self.x))
+        sums = self.sums if control is None \
+            else [float(np.sum(self.x.astype(np.float16)))]
+        return {"echo_gap": max(abs(s - want) / want for s in sums)}
+
+
+def setup(config, traffic, seed, rec):
+    return Echo(traffic, seed)
+'''
+ECHO_TRAFFIC = {"kind": "echo", "n": 4096, "trace_seconds": 1,
+                "limits": {"echo_gap": 1e-5}}
 
 
 def test_benchmark_json_shape():
@@ -66,17 +108,31 @@ def test_every_cell_loads_with_its_files():
 
 @pytest.fixture(scope="module")
 def reported_by_kind(tmp_path_factory):
-    """The end-to-end metrics each traffic kind's driver reports, read
-    from a short window of a tiny cell of that kind; ``run.py`` adds
-    ``setup_s``."""
+    """The end-to-end metrics a traffic kind's driver reports, with
+    ``setup_s``, which ``run.py`` adds; read once a kind, on its tiny
+    cell, while ``check_kind`` holds the driver to its contract."""
     root = make_checkout(tmp_path_factory.mktemp("kinds"))
-    out = {}
-    for kind, tiny in TINY_OF_KIND.items():
-        cell = bench.load_cell(root, tiny)
-        drv = bench.driver(cell).setup(cell.config, cell.traffic, 3,
-                                       Recorder())
-        out[kind] = set(drv.window(0.2)) | {"setup_s"}
-    return out
+    seen = {}
+
+    def reported(kind):
+        if kind not in seen:
+            seen[kind] = check_kind(root, kind)
+        return seen[kind]
+    return reported
+
+
+@pytest.mark.parametrize("kind", driver_kinds(REPO))
+def test_every_kind_keeps_the_driver_contract(reported_by_kind, kind):
+    assert "setup_s" in reported_by_kind(kind)
+
+
+def test_a_new_kind_is_one_driver_file_and_one_traffic_file(tmp_path):
+    root = make_checkout(tmp_path, files={
+        "chipbench/drivers/echo.py": ECHO_DRIVER,
+        "chipbench/traffic/echo.json": json.dumps(ECHO_TRAFFIC)})
+    assert "echo" in driver_kinds(root)
+    assert check_kind(root, "echo") == {"echo_per_s", "setup_s"}
+    assert bench.load_cell(root, "tiny_echo").traffic["n"] == 64
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in SPEC["workloads"]])
@@ -84,7 +140,7 @@ def test_every_end_to_end_metric_is_one_its_driver_reports(
         reported_by_kind, workload):
     cell = bench.load_cell(REPO, workload)
     given = {m["name"] for m in cell.end_to_end}
-    assert given <= reported_by_kind[cell.traffic["kind"]], workload
+    assert given <= reported_by_kind(cell.traffic["kind"]), workload
 
 
 def test_sweep_cells_report_the_sweep_rate_and_setup_alone():
@@ -115,6 +171,30 @@ def test_configs_match_the_program():
     for k, v in (("T", want.T), ("E", want.E), ("mAP", want.mAP),
                  ("floor_mw", want.floor_mw)):
         np.testing.assert_array_equal(got[k], np.asarray(v))
+
+
+def test_a_deployment_names_its_fleet(tmp_path):
+    from repro.core.profiles import paper_fleet
+
+    root = make_checkout(tmp_path)
+    cell = bench.load_cell(root, "tiny_faults")
+    assert cell.config["name"] == "paper_testbed_faults"
+    assert cell.config["faults"] == TINY_FAULTS["faults"]
+    assert "tables" not in json.loads(
+        (root / "chipbench" / "configs" / "paper_testbed_faults.json")
+        .read_text())
+    got, ref = fleets.tables(cell.config), paper_fleet()
+    for k, v in (("T", ref.T), ("E", ref.E), ("mAP", ref.mAP),
+                 ("floor_mw", ref.floor_mw)):
+        np.testing.assert_array_equal(got[k], np.asarray(v))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    configs = {c["name"]: c for c in spec["configs"]}
+    (root / "chipbench" / "configs" / "nowhere.json").write_text(
+        json.dumps({"name": "nowhere", "fleet": "no_such_fleet"}))
+    configs["nowhere"] = dict(configs["tiny_city"], name="nowhere",
+                              file="chipbench/configs/nowhere.json")
+    with pytest.raises(KeyError, match="no_such_fleet"):
+        bench.load_config(root, configs, "nowhere")
 
 
 def test_a_new_cell_is_files_and_one_entry(tmp_path):

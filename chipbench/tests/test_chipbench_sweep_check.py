@@ -51,7 +51,7 @@ def test_control_fails(root):
     drv.window(0.1)
     lim = cell.traffic["limits"]
     assert all(v <= lim[k] for k, v in drv.check().items())
-    assert any(v > lim[k] for k, v in drv.check(control=True).items())
+    assert any(v > lim[k] for k, v in drv.check(control="bfloat16").items())
 
 
 def _patch(monkeypatch, fault):
